@@ -377,26 +377,23 @@ def prefix_sharing() -> Tuple[List[Tuple[str, float, str]], Dict]:
 def observability() -> Tuple[List[Tuple[str, float, str]], Dict]:
     """The §Observability overhead study + trace artifact production.
 
-    Five identical paged engines differing only in observability level —
-    fully disabled, metrics-only (the default), full tracing, tracing +
-    rolling windows, and tracing + the dispatch profiler sampling EVERY
-    tick — run the same closed loop through ``_closed_loop_pair``; the
-    payload records the per-tick cost ratios (windows gate against
-    disabled; the profiler, whose fence deliberately serializes dispatch
-    and compute, gates against traced with its own ``sampling_gate``). A
-    no-op-hook microbench then times the disabled instruments directly:
+    Four identical paged engines differing only in observability level —
+    fully disabled, metrics-only (the default), full tracing (the tick
+    spans and their profiler annotations), and tracing + rolling windows
+    — run the same closed loop through ``_closed_loop_pair``; the payload
+    records the per-tick cost ratios against disabled. A no-op-hook
+    microbench then times the disabled instruments directly:
     ``disabled_hook_frac`` is the fraction of a disabled-mode tick a
     *generous* per-tick hook budget would cost, and the acceptance gate
-    requires it ≤ 2% (``gate_frac``). The profiled engine's fenced ticks
-    feed ``dispatch_floor`` (the EXPERIMENTS.md §Dispatch floor baseline).
-    A small virtual-clock traced run exports ``reports/TRACE_engine.json``
-    + ``METRICS_engine.jsonl``, schema-validates both, and asserts the
-    tracer dropped nothing (the CI gate re-validates the shipped artifacts
-    via ``python -m repro.obs.export --assert-zero``). Finally
+    requires it ≤ 2% (``gate_frac``). A small virtual-clock traced run
+    exports ``reports/TRACE_engine.json`` + ``METRICS_engine.jsonl``,
+    schema-validates both, and asserts the tracer dropped nothing (the
+    CI gate re-validates the shipped artifacts via ``python -m
+    repro.obs.export --assert-zero``). Finally
     ``_burn_rate_smoke`` runs the full online-reaction path — fault →
     SLO burn → alert → flight dump — and hard-asserts it end to end.
     """
-    from repro.obs import Observability, dispatch_floor_summary
+    from repro.obs import Observability
     from repro.obs.export import (assert_zero, validate_metrics_file,
                                   validate_trace_file, write_chrome_trace,
                                   write_metrics_jsonl)
@@ -414,8 +411,7 @@ def observability() -> Tuple[List[Tuple[str, float, str]], Dict]:
     engines = {"disabled": mk(obs=Observability.disabled()),
                "metrics": mk(),
                "traced": mk(trace=True),
-               "windowed": mk(obs=Observability(trace=True, windows=True)),
-               "profiled": mk(trace=True, profile_dispatch=1)}
+               "windowed": mk(obs=Observability(trace=True, windows=True))}
 
     def short(rng):
         return int(rng.integers(PG_SHORT_NEW - 4, PG_SHORT_NEW + 5))
@@ -423,19 +419,13 @@ def observability() -> Tuple[List[Tuple[str, float, str]], Dict]:
     ticks = _closed_loop_pair(engines, k=PG_BATCH // 2, max_new=short,
                               n_steps=60, seed=3)
     base_ms = max(ticks["disabled"]["mean_step_ms"], 1e-9)
-    traced_ms = max(ticks["traced"]["mean_step_ms"], 1e-9)
     payload: Dict = {
         "ticks": ticks,
         "metrics_over_disabled": ticks["metrics"]["mean_step_ms"] / base_ms,
         "traced_over_disabled": ticks["traced"]["mean_step_ms"] / base_ms,
         "windowed_over_disabled":
             ticks["windowed"]["mean_step_ms"] / base_ms,
-        "profiled_over_traced":
-            ticks["profiled"]["mean_step_ms"] / traced_ms,
-        "sampling_gate": 1.5,
     }
-    payload["dispatch_floor"] = dispatch_floor_summary(
-        engines["profiled"].tracer.ticks)
 
     # --- no-op hook microbench: what do the disabled instruments cost? ---
     obs = Observability.disabled()
@@ -501,10 +491,6 @@ def observability() -> Tuple[List[Tuple[str, float, str]], Dict]:
 
     payload["burn_smoke"] = _burn_rate_smoke()
 
-    fl = payload["dispatch_floor"]
-    floor_note = " ".join(
-        f"{k}:off_device={d['dispatch_frac'] + d['host_sync_frac']:.2f}"
-        f"(n={d['n_sampled']})" for k, d in sorted(fl.items())) or "no samples"
     rows = [
         ("obs_disabled_hook_frac", frac * 1e6,
          f"hook={per_hook_s * 1e9:.0f}ns x{hooks_per_tick}/tick "
@@ -516,10 +502,6 @@ def observability() -> Tuple[List[Tuple[str, float, str]], Dict]:
          f"({n_ev} events exported)"),
         ("obs_windowed_tick_ratio", payload["windowed_over_disabled"] * 1e6,
          f"windowed/disabled={payload['windowed_over_disabled']:.3f}"),
-        ("obs_profiled_tick_ratio", payload["profiled_over_traced"] * 1e6,
-         f"profiled/traced={payload['profiled_over_traced']:.3f} "
-         f"(sampling every tick; gate<={payload['sampling_gate']})"),
-        ("obs_dispatch_floor", 0.0, floor_note),
         ("obs_burn_smoke", payload["burn_smoke"]["alerts_fired"] * 1e6,
          f"alerts={payload['burn_smoke']['alerts_fired']} "
          f"resolves={payload['burn_smoke']['burn_resolves']} "
@@ -698,12 +680,13 @@ def async_overlap() -> Tuple[List[Tuple[str, float, str]], Dict]:
     no-regression sanity bound and the payload carries
     ``"single_core": true`` so the report can say which bound applied.
 
-    Attribution for the EXPERIMENTS.md dispatch-floor table: the sync
-    baseline's off-device fraction comes from the fenced profiler
-    (dispatch + host-sync share of exec); the async column's *exposed*
-    off-device fraction is ``commit_wait_ms`` (time actually blocked on
-    the un-synced token array) over mean step — every other host phase
-    runs with an exec structurally in flight (= ``hidden_host_ms``).
+    Attribution for the EXPERIMENTS.md async table, from the traced tick
+    spans of both modes: the sync baseline's off-device fraction is the
+    share of a decode tick's exec (its dispatch and commit spans) spent
+    outside ``commit_wait``, the read that blocks until the device has
+    run the step; the async column's *exposed* off-device fraction is
+    ``commit_wait_ms`` over mean step — every other host phase runs with
+    an exec structurally in flight (= ``hidden_host_ms``).
 
     Also exports the async traced artifacts (TRACE_engine_async.json,
     METRICS_engine_async.jsonl) that CI schema-validates with
@@ -711,7 +694,6 @@ def async_overlap() -> Tuple[List[Tuple[str, float, str]], Dict]:
     ``jnp.pad``-on-device admission fix."""
     import math as _math
 
-    from repro.obs import dispatch_floor_summary
     from repro.obs.export import (assert_zero, validate_metrics_file,
                                   validate_trace_file, write_chrome_trace,
                                   write_metrics_jsonl)
@@ -749,11 +731,9 @@ def async_overlap() -> Tuple[List[Tuple[str, float, str]], Dict]:
         f"({cores} core(s); async={med['async']:.3f}ms "
         f"sync={med['sync']:.3f}ms)")
 
-    # --- attribution runs: fenced sync baseline + traced async commit ---
+    # --- attribution runs: traced sync baseline + traced async commit ---
     def attributed(async_tick: bool) -> Dict:
-        kw = dict(trace=True) if async_tick else dict(trace=True,
-                                                      profile_dispatch=1)
-        eng = _async_engine(async_tick, **kw)
+        eng = _async_engine(async_tick, trace=True)
         rng = np.random.default_rng(9)
         for i in range(AS_BATCH):
             eng.submit(Request(rid=i, tokens=rng.integers(0, VOCAB, AS_PROMPT),
@@ -763,9 +743,14 @@ def async_overlap() -> Tuple[List[Tuple[str, float, str]], Dict]:
         eng.drain(0.0)
         recs = list(eng.tracer.ticks)
         admit = [r.admit_ms for r in recs if _math.isfinite(r.admit_ms)]
-        out = {"dispatch_floor": dispatch_floor_summary(recs),
-               "admit_ms_mean": float(np.mean(admit)) if admit else 0.0}
-        if async_tick:
+        out = {"admit_ms_mean": float(np.mean(admit)) if admit else 0.0}
+        if not async_tick:
+            dec = [r.spans for r in recs if r.kind == "decode"]
+            out["off_device_frac"] = float(np.mean([
+                1.0 - (s["commit_wait"][1] - s["commit_wait"][0])
+                / max(s["commit"][1] - s["dispatch"][0], 1e-12)
+                for s in dec]))
+        else:
             com = [r for r in recs if _math.isfinite(r.commit_ms)]
             out["commit"] = {
                 "n_ticks": len(com),
@@ -783,22 +768,19 @@ def async_overlap() -> Tuple[List[Tuple[str, float, str]], Dict]:
     sync_attr = attributed(False)
     async_attr = attributed(True)
     art_eng = async_attr.pop("engine")
-    payload["sync"]["dispatch_floor"] = sync_attr["dispatch_floor"]
     payload["sync"]["admit_ms_mean"] = sync_attr["admit_ms_mean"]
-    payload["async"].update(
-        {k: v for k, v in async_attr.items() if k != "dispatch_floor"})
+    payload["async"].update(async_attr)
     # admit-phase cost: async ticks fall back to chunked admission, so a
     # joiner costs one pipelined chunk dispatch instead of a blocking
     # monolithic prefill inside the tick
     payload["admit_ratio"] = (async_attr["admit_ms_mean"]
                               / max(sync_attr["admit_ms_mean"], 1e-9))
 
-    # exposed off-device fraction per mode (the dispatch-floor table's
-    # async column): sync exposes dispatch + host-sync every tick; async
-    # exposes only the commit wait — the rest runs behind the in-flight
-    # exec. decode rows only (the steady-state tick kind at this geometry).
-    dd = sync_attr["dispatch_floor"].get("decode", {})
-    sync_off = dd.get("dispatch_frac", 0.0) + dd.get("host_sync_frac", 0.0)
+    # exposed off-device fraction per mode: sync exposes its whole exec
+    # outside the commit wait every tick; async exposes only the commit
+    # wait — the rest runs behind the in-flight exec. decode rows only
+    # (the steady-state tick kind at this geometry).
+    sync_off = sync_attr["off_device_frac"]
     async_off = (async_attr["commit"]["commit_wait_ms_mean"]
                  / max(med["async"], 1e-9))
     payload["off_device_frac"] = {"sync": sync_off, "async": async_off}

@@ -261,9 +261,8 @@ def observability_table(bench_path: str) -> str:
     ratios = {"disabled": (1.0, "—"),
               "metrics": (c.get("metrics_over_disabled"), "vs disabled"),
               "traced": (c.get("traced_over_disabled"), "vs disabled"),
-              "windowed": (c.get("windowed_over_disabled"), "vs disabled"),
-              "profiled": (c.get("profiled_over_traced"), "vs traced")}
-    for level in ("disabled", "metrics", "traced", "windowed", "profiled"):
+              "windowed": (c.get("windowed_over_disabled"), "vs disabled")}
+    for level in ("disabled", "metrics", "traced", "windowed"):
         t = ticks.get(level)
         if not t:
             continue
@@ -334,18 +333,16 @@ def spec_decode_table(bench_path: str) -> str:
     return "\n".join(out)
 
 
-def dispatch_floor_table(bench_path: str) -> str:
-    """§Dispatch floor: per-tick-type host/device split from the sampled
-    (fenced) ticks — the ``dispatch_floor`` cell of BENCH_engine.json. The
-    off-device fraction (dispatch + host-sync share of the exec phase) is
-    the budget the async two-phase tick loop overlaps away; when the
-    ``async_overlap`` study has run, a second table compares the sync
-    baseline's exposed fraction against the async loop's (only the commit
-    wait stays exposed — dispatch, bookkeeping, and the D2H read ride
+def async_overlap_table(bench_path: str) -> str:
+    """§Async tick loop: the sync baseline's exposed off-device fraction
+    against the async loop's, from the ``async_overlap`` cell of
+    BENCH_engine.json (both read from the traced tick spans: the sync
+    tick exposes its exec outside the commit's blocking read; the async
+    loop only that read — dispatch, bookkeeping, and the D2H read ride
     behind the in-flight exec; DESIGN.md §Async tick loop)."""
-    out = ["| tick kind | n | dispatch ms mean/p50 | device ms mean/p50 | "
-           "host-sync ms mean/p50 | exec ms | off-device frac |",
-           "|---|---|---|---|---|---|---|"]
+    out = ["| mode | mean step ms | exposed off-device frac | "
+           "hidden host ms/tick | commit wait ms |",
+           "|---|---|---|---|---|"]
     if not os.path.exists(bench_path):
         return "\n".join(out)
     try:
@@ -353,40 +350,25 @@ def dispatch_floor_table(bench_path: str) -> str:
             data = json.load(f)
     except (ValueError, json.JSONDecodeError):
         return "\n".join(out)
-    floor = (data.get("observability") or {}).get("dispatch_floor") or {}
-    for kind, d in sorted(floor.items()):
-        off = d["dispatch_frac"] + d["host_sync_frac"]
-        out.append(
-            f"| {kind} | {d['n_sampled']} | "
-            f"{d['dispatch_ms_mean']:.2f}/{d['dispatch_ms_p50']:.2f} | "
-            f"{d['device_ms_mean']:.2f}/{d['device_ms_p50']:.2f} | "
-            f"{d['host_sync_ms_mean']:.2f}/{d['host_sync_ms_p50']:.2f} | "
-            f"{d['exec_ms_mean']:.2f} | **{off:.2f}** |")
     ao = data.get("async_overlap") or {}
-    if ao:
-        com = (ao.get("async") or {}).get("commit") or {}
-        offd = ao.get("off_device_frac") or {}
-        gate_note = ("single-core host: no-regression bound"
-                     if ao.get("single_core")
-                     else f"multi-core gate <= {ao.get('gate', 0.9)}")
-        out += ["",
-                "Async two-phase tick loop vs sync at the overlap geometry "
-                "(`async_overlap` study; decode ticks):",
-                "",
-                "| mode | mean step ms | exposed off-device frac | "
-                "hidden host ms/tick | commit wait ms |",
-                "|---|---|---|---|---|",
-                f"| sync | {ao.get('sync', {}).get('mean_step_ms', 0):.3f} | "
-                f"**{offd.get('sync', 0):.3f}** | — | — |",
-                f"| async | {ao.get('async', {}).get('mean_step_ms', 0):.3f}"
-                f" | **{offd.get('async', 0):.3f}** | "
-                f"{com.get('hidden_host_ms_mean', 0):.3f} | "
-                f"{com.get('commit_wait_ms_mean', 0):.3f} |",
-                "",
-                f"step ratio async/sync = {ao.get('step_ratio', 0):.3f} "
-                f"({ao.get('cores', '?')} core(s); {gate_note}); greedy "
-                f"outputs bitwise identical on "
-                f"{(ao.get('parity') or {}).get('n_requests', 0)} requests."]
+    if not ao:
+        return "\n".join(out)
+    com = (ao.get("async") or {}).get("commit") or {}
+    offd = ao.get("off_device_frac") or {}
+    gate_note = ("single-core host: no-regression bound"
+                 if ao.get("single_core")
+                 else f"multi-core gate <= {ao.get('gate', 0.9)}")
+    out += [f"| sync | {ao.get('sync', {}).get('mean_step_ms', 0):.3f} | "
+            f"**{offd.get('sync', 0):.3f}** | — | — |",
+            f"| async | {ao.get('async', {}).get('mean_step_ms', 0):.3f}"
+            f" | **{offd.get('async', 0):.3f}** | "
+            f"{com.get('hidden_host_ms_mean', 0):.3f} | "
+            f"{com.get('commit_wait_ms_mean', 0):.3f} |",
+            "",
+            f"step ratio async/sync = {ao.get('step_ratio', 0):.3f} "
+            f"({ao.get('cores', '?')} core(s); {gate_note}); greedy "
+            f"outputs bitwise identical on "
+            f"{(ao.get('parity') or {}).get('n_requests', 0)} requests."]
     return "\n".join(out)
 
 
@@ -475,8 +457,8 @@ def main():
     inject(args.md, "OBS_OVERHEAD_TABLE",
            observability_table(args.bench_engine))
     inject(args.md, "OBS_AUDIT_TABLE", audit_table(args.audit))
-    inject(args.md, "DISPATCH_FLOOR_TABLE",
-           dispatch_floor_table(args.bench_engine))
+    inject(args.md, "ASYNC_OVERLAP_TABLE",
+           async_overlap_table(args.bench_engine))
     inject(args.md, "SPEC_DECODE_TABLE",
            spec_decode_table(args.bench_engine))
     n_ok = sum(1 for d in rows if not d.get("skipped") and "error" not in d)

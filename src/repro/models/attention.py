@@ -635,47 +635,56 @@ def paged_decode_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     B = x.shape[0]
     ps = k_pages.shape[2]
     max_pages = page_table.shape[1]
-    q = _split_heads(jnp.einsum("bsd,de->bse", x, p["wq"].astype(dt)), H, hd)
-    k = _split_heads(jnp.einsum("bsd,de->bse", x, p["wk"].astype(dt)), KV, hd)
-    v = _split_heads(jnp.einsum("bsd,de->bse", x, p["wv"].astype(dt)), KV, hd)
-    q = apply_rope(q, pos[:, None], cfg.rope_theta)
-    k = apply_rope(k, pos[:, None], cfg.rope_theta)
+    with jax.named_scope("attn_proj"):
+        q = _split_heads(jnp.einsum("bsd,de->bse", x, p["wq"].astype(dt)),
+                         H, hd)
+        k = _split_heads(jnp.einsum("bsd,de->bse", x, p["wk"].astype(dt)),
+                         KV, hd)
+        v = _split_heads(jnp.einsum("bsd,de->bse", x, p["wv"].astype(dt)),
+                         KV, hd)
+        q = apply_rope(q, pos[:, None], cfg.rope_theta)
+        k = apply_rope(k, pos[:, None], cfg.rope_theta)
 
     # scatter the new token into its page (clip keeps long-dead rows inside
     # the table; their row is all trash-page anyway)
-    page_col = jnp.minimum(pos // ps, max_pages - 1)
-    page = page_table[jnp.arange(B), page_col]               # (B,)
-    off = pos % ps
-    k_pages = k_pages.astype(dt).at[:, page, off].set(
-        k[:, 0].transpose(1, 0, 2))                          # value (KV,B,hd)
-    v_pages = v_pages.astype(dt).at[:, page, off].set(
-        v[:, 0].transpose(1, 0, 2))
+    with jax.named_scope("kv_write"):
+        page_col = jnp.minimum(pos // ps, max_pages - 1)
+        page = page_table[jnp.arange(B), page_col]           # (B,)
+        off = pos % ps
+        k_pages = k_pages.astype(dt).at[:, page, off].set(
+            k[:, 0].transpose(1, 0, 2))                      # value (KV,B,hd)
+        v_pages = v_pages.astype(dt).at[:, page, off].set(
+            v[:, 0].transpose(1, 0, 2))
 
-    lengths = pos + 1
-    tables = page_table[:, :n_pages]
-    qg = q.reshape(B, KV, H // KV, hd)                       # (B,KV,G,hd)
-    if cfg.use_pallas:
-        from repro.kernels import ops as kops
-        out = kops.paged_flash_decode(qg, k_pages, v_pages, tables, lengths,
-                                      softcap=cfg.attn_logit_softcap)
-    else:
-        kg = jnp.moveaxis(k_pages[:, tables], 1, 0)      # (B,KV,n_pages,ps,hd)
-        vg = jnp.moveaxis(v_pages[:, tables], 1, 0)
-        kg = kg.reshape(B, KV, n_pages * ps, hd)
-        vg = vg.reshape(B, KV, n_pages * ps, hd)
-        scores = jnp.einsum("bkgh,bkth->bkgt", qg, kg,
-                            preferred_element_type=jnp.float32) / np.sqrt(hd)
-        if cfg.attn_logit_softcap:
-            c = cfg.attn_logit_softcap
-            scores = jnp.tanh(scores / c) * c
-        valid = jnp.arange(n_pages * ps)[None, :] < lengths[:, None]
-        scores = jnp.where(valid[:, None, None], scores, -1e9)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bkgt,bkth->bkgh", probs.astype(vg.dtype), vg,
-                         preferred_element_type=jnp.float32)
-        out = out.astype(dt)
-    out = out.reshape(B, 1, H * hd)
-    attn = jnp.einsum("bse,ed->bsd", out, p["wo"].astype(dt))
+    with jax.named_scope("attn_kernel"):
+        lengths = pos + 1
+        tables = page_table[:, :n_pages]
+        qg = q.reshape(B, KV, H // KV, hd)                   # (B,KV,G,hd)
+        if cfg.use_pallas:
+            from repro.kernels import ops as kops
+            out = kops.paged_flash_decode(qg, k_pages, v_pages, tables,
+                                          lengths,
+                                          softcap=cfg.attn_logit_softcap)
+        else:
+            kg = jnp.moveaxis(k_pages[:, tables], 1, 0)  # (B,KV,np,ps,hd)
+            vg = jnp.moveaxis(v_pages[:, tables], 1, 0)
+            kg = kg.reshape(B, KV, n_pages * ps, hd)
+            vg = vg.reshape(B, KV, n_pages * ps, hd)
+            scores = jnp.einsum("bkgh,bkth->bkgt", qg, kg,
+                                preferred_element_type=jnp.float32
+                                ) / np.sqrt(hd)
+            if cfg.attn_logit_softcap:
+                c = cfg.attn_logit_softcap
+                scores = jnp.tanh(scores / c) * c
+            valid = jnp.arange(n_pages * ps)[None, :] < lengths[:, None]
+            scores = jnp.where(valid[:, None, None], scores, -1e9)
+            probs = jax.nn.softmax(scores, axis=-1)
+            out = jnp.einsum("bkgt,bkth->bkgh", probs.astype(vg.dtype), vg,
+                             preferred_element_type=jnp.float32)
+            out = out.astype(dt)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(B, 1, H * hd)
+        attn = jnp.einsum("bse,ed->bsd", out, p["wo"].astype(dt))
     return attn, k_pages, v_pages
 
 
@@ -796,35 +805,39 @@ def paged_chunk_prefill_attention(cfg: ModelConfig, p: Dict, x: jax.Array,
     T = max_pages * ps
     offs = jnp.arange(ck)
     positions = start[:, None] + offs[None, :]               # (B, ck)
-    q, k, v = _chunk_qkv(cfg, p, x, positions)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _chunk_qkv(cfg, p, x, positions)
 
-    page_col = jnp.minimum(positions // ps, max_pages - 1)
-    page = page_table[jnp.arange(B)[:, None], page_col]      # (B, ck)
-    page = jnp.where(offs[None, :] < n_valid[:, None], page,
-                     k_pages.shape[1])                       # OOB: dropped
-    off = positions % ps
-    k_pages = k_pages.astype(dt).at[:, page, off].set(
-        k.transpose(2, 0, 1, 3), mode="drop")                # (KV, B, ck, hd)
-    v_pages = v_pages.astype(dt).at[:, page, off].set(
-        v.transpose(2, 0, 1, 3), mode="drop")
+    with jax.named_scope("kv_write"):
+        page_col = jnp.minimum(positions // ps, max_pages - 1)
+        page = page_table[jnp.arange(B)[:, None], page_col]  # (B, ck)
+        page = jnp.where(offs[None, :] < n_valid[:, None], page,
+                         k_pages.shape[1])                   # OOB: dropped
+        off = positions % ps
+        k_pages = k_pages.astype(dt).at[:, page, off].set(
+            k.transpose(2, 0, 1, 3), mode="drop")            # (KV,B,ck,hd)
+        v_pages = v_pages.astype(dt).at[:, page, off].set(
+            v.transpose(2, 0, 1, 3), mode="drop")
 
-    qg = q.reshape(B, ck, KV, H // KV, hd)
-    if cfg.use_pallas:
-        from repro.kernels import ops as kops
-        lengths = jnp.clip(positions + 1, 1, T)              # (B, ck)
-        outs = [kops.paged_flash_decode(qg[:, j], k_pages, v_pages,
-                                        page_table, lengths[:, j],
-                                        softcap=cfg.attn_logit_softcap)
-                for j in range(ck)]
-        out = jnp.stack(outs, axis=1).reshape(B, ck, H, hd)
-    else:
-        kg = jnp.moveaxis(k_pages[:, page_table], 1, 0)      # (B,KV,mp,ps,hd)
-        vg = jnp.moveaxis(v_pages[:, page_table], 1, 0)
-        kg = kg.reshape(B, KV, T, hd)
-        vg = vg.reshape(B, KV, T, hd)
-        valid = jnp.arange(T)[None, None, :] <= positions[:, :, None]
-        bias = jnp.where(valid, 0.0, -1e9).astype(jnp.float32)
-        out = _chunk_attend(cfg, q, kg, vg, bias)
-    out = out.reshape(B, ck, H * hd)
-    attn = jnp.einsum("bse,ed->bsd", out, p["wo"].astype(dt))
+    with jax.named_scope("attn_kernel"):
+        qg = q.reshape(B, ck, KV, H // KV, hd)
+        if cfg.use_pallas:
+            from repro.kernels import ops as kops
+            lengths = jnp.clip(positions + 1, 1, T)          # (B, ck)
+            outs = [kops.paged_flash_decode(qg[:, j], k_pages, v_pages,
+                                            page_table, lengths[:, j],
+                                            softcap=cfg.attn_logit_softcap)
+                    for j in range(ck)]
+            out = jnp.stack(outs, axis=1).reshape(B, ck, H, hd)
+        else:
+            kg = jnp.moveaxis(k_pages[:, page_table], 1, 0)  # (B,KV,mp,ps,hd)
+            vg = jnp.moveaxis(v_pages[:, page_table], 1, 0)
+            kg = kg.reshape(B, KV, T, hd)
+            vg = vg.reshape(B, KV, T, hd)
+            valid = jnp.arange(T)[None, None, :] <= positions[:, :, None]
+            bias = jnp.where(valid, 0.0, -1e9).astype(jnp.float32)
+            out = _chunk_attend(cfg, q, kg, vg, bias)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(B, ck, H * hd)
+        attn = jnp.einsum("bse,ed->bsd", out, p["wo"].astype(dt))
     return attn, k_pages, v_pages

@@ -411,10 +411,12 @@ class LM:
         logits (B, V) (garbage rows where n_valid == 0)."""
         cfg = self.cfg
         B, ck = x.shape[0], x.shape[1]
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        last = jnp.clip(n_valid - 1, 0, ck - 1)
-        logits = unembed(cfg, params["embed"], x[jnp.arange(B), last][:, None])
-        return (logits + jnp.asarray(self._vmask, logits.dtype))[:, 0]
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            last = jnp.clip(n_valid - 1, 0, ck - 1)
+            logits = unembed(cfg, params["embed"],
+                             x[jnp.arange(B), last][:, None])
+            return (logits + jnp.asarray(self._vmask, logits.dtype))[:, 0]
 
     def _chunk_trunk(self, params: Dict, cache: Dict, tokens: jax.Array,
                      start: jax.Array, n_valid: jax.Array, *, paged: bool
@@ -431,10 +433,12 @@ class LM:
         assert self.supports_chunked_prefill(), \
             f"chunked prefill unsupported for config {cfg.name!r}"
         dt = self.compute_dtype
-        x = embed(cfg, params["embed"], tokens, dt)
-        if cfg.rope_theta <= 0:
-            positions = start[:, None] + jnp.arange(tokens.shape[1])[None, :]
-            x = x + self._sinusoid_pe(positions).astype(dt)
+        with jax.named_scope("embed"):
+            x = embed(cfg, params["embed"], tokens, dt)
+            if cfg.rope_theta <= 0:
+                positions = (start[:, None]
+                             + jnp.arange(tokens.shape[1])[None, :])
+                x = x + self._sinusoid_pe(positions).astype(dt)
 
         if paged:
             pt = cache["pt"]
@@ -442,16 +446,18 @@ class LM:
             def body(carry, inp):
                 lp, kp_l, vp_l = inp
                 x_in = carry
-                h = rms_norm(x_in, lp["ln1"], cfg.norm_eps)
+                with jax.named_scope("attn_proj"):
+                    h = rms_norm(x_in, lp["ln1"], cfg.norm_eps)
                 a, kp_l, vp_l = attn.paged_chunk_prefill_attention(
                     cfg, lp["attn"], h, kp_l, vp_l, pt, start, n_valid)
                 x_new = x_in + a
-                h2 = rms_norm(x_new, lp["ln2"], cfg.norm_eps)
-                if cfg.family == "moe":
-                    y, _ = moe_mod.apply_moe(cfg, lp["ffn"], h2)
-                else:
-                    y = apply_mlp(cfg, lp["ffn"], h2)
-                return x_new + y, {"kp": kp_l, "vp": vp_l}
+                with jax.named_scope("mlp"):
+                    h2 = rms_norm(x_new, lp["ln2"], cfg.norm_eps)
+                    if cfg.family == "moe":
+                        y, _ = moe_mod.apply_moe(cfg, lp["ffn"], h2)
+                    else:
+                        y = apply_mlp(cfg, lp["ffn"], h2)
+                    return x_new + y, {"kp": kp_l, "vp": vp_l}
 
             if not cfg.scan_layers:
                 outs = []
@@ -754,24 +760,27 @@ class LM:
         assert self.supports_paged_cache(), cfg.name
         dt = self.compute_dtype
         pos = cache["pos"]
-        x = embed(cfg, params["embed"], tokens[:, None], dt)
-        if cfg.rope_theta <= 0:
-            x = x + self._sinusoid_pe(pos)[:, None, :].astype(dt)
+        with jax.named_scope("embed"):
+            x = embed(cfg, params["embed"], tokens[:, None], dt)
+            if cfg.rope_theta <= 0:
+                x = x + self._sinusoid_pe(pos)[:, None, :].astype(dt)
         pt = cache["pt"]
 
         def body(carry, inp):
             lp, kp_l, vp_l = inp
             x_in = carry
-            h = rms_norm(x_in, lp["ln1"], cfg.norm_eps)
+            with jax.named_scope("attn_proj"):
+                h = rms_norm(x_in, lp["ln1"], cfg.norm_eps)
             a, kp_l, vp_l = attn.paged_decode_attention(
                 cfg, lp["attn"], h, kp_l, vp_l, pt, pos, n_pages=n_pages)
             x_new = x_in + a
-            h2 = rms_norm(x_new, lp["ln2"], cfg.norm_eps)
-            if cfg.family == "moe":
-                y, _ = moe_mod.apply_moe(cfg, lp["ffn"], h2)
-            else:
-                y = apply_mlp(cfg, lp["ffn"], h2)
-            x_new = x_new + y
+            with jax.named_scope("mlp"):
+                h2 = rms_norm(x_new, lp["ln2"], cfg.norm_eps)
+                if cfg.family == "moe":
+                    y, _ = moe_mod.apply_moe(cfg, lp["ffn"], h2)
+                else:
+                    y = apply_mlp(cfg, lp["ffn"], h2)
+                x_new = x_new + y
             return x_new, {"kp": kp_l, "vp": vp_l}
 
         if not cfg.scan_layers:
@@ -785,9 +794,10 @@ class LM:
         else:
             x, new_caches = jax.lax.scan(
                 body, x, (params["layers"], cache["kp"], cache["vp"]))
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(cfg, params["embed"], x)
-        logits = logits + jnp.asarray(self._vmask, logits.dtype)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            logits = unembed(cfg, params["embed"], x)
+            logits = logits + jnp.asarray(self._vmask, logits.dtype)
         new_cache = dict(cache)
         new_cache.update(new_caches)
         new_cache["pos"] = pos + 1

@@ -26,7 +26,6 @@ from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        NullInstrument, NULL_REGISTRY)
 from .slo import (Alert, AlertSink, BurnRateRule, CollectingSink,
                   SLOMonitor, slo_class_key)
-from .profiler import dispatch_floor_summary
 from .trace import (EVENT_TAXONOMY, NULL_TRACER, SpanEvent, TickRecord,
                     Tracer, to_chrome_trace, validate_chrome_trace)
 from .windows import (MetricWindows, NULL_WINDOWS, WindowedCounter,
@@ -39,8 +38,7 @@ __all__ = ["Observability", "MetricsRegistry", "NULL_REGISTRY", "Counter",
            "predict_outputs", "attach_from_requests", "MetricWindows",
            "NULL_WINDOWS", "WindowedCounter", "WindowedHistogram", "Alert",
            "AlertSink", "BurnRateRule", "CollectingSink", "SLOMonitor",
-           "slo_class_key", "FlightRecorder", "FlightTrigger",
-           "dispatch_floor_summary"]
+           "slo_class_key", "FlightRecorder", "FlightTrigger"]
 
 
 class Observability:

@@ -15,12 +15,14 @@ Two record kinds, one clock domain (DESIGN.md §Observability):
   tick took (fused chunk vs pure decode), wall-clock cost of the
   preempt/admit/execute phases (``time.perf_counter`` — wall cost even
   when the *timeline* clock is virtual), batch geometry, queue depth, and
-  paged-pool occupancy. On dispatch-profiled ticks
-  (``InProcessServingEngine(profile_dispatch=N)``) the execute phase is
-  further split into ``dispatch_ms`` (jit call returning — jax async
-  dispatch), ``device_ms`` (``block_until_ready`` fence — device
-  compute), and ``host_sync_ms`` (``np.asarray`` copy + host
-  bookkeeping); NaN on unsampled ticks (see ``obs.profiler``).
+  paged-pool occupancy. ``spans`` holds where each host phase started and
+  ended on ``time.perf_counter``: the engine tick (``tick``, shared by
+  every backend's record of that tick) and, nested in it, ``preempt``,
+  ``admit``, ``dispatch`` (holding ``launch``, the jitted call alone) and
+  ``commit`` (holding ``commit_wait``, the blocking read of the tokens).
+  The engine wraps the same phases in ``jax.profiler.TraceAnnotation``
+  (``engine.<phase>``), so a profiler trace holds them on the device
+  trace's clock.
 
 ``Tracer`` stores both, bounded (drops-past-cap are counted, never
 silently lost — and surfaced as registry counters ``obs.spans_dropped``/
@@ -42,10 +44,11 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["SpanEvent", "TickRecord", "Tracer", "NULL_TRACER",
-           "EVENT_TAXONOMY", "to_chrome_trace", "validate_chrome_trace"]
+           "EVENT_TAXONOMY", "TICK_PHASES", "to_chrome_trace",
+           "validate_chrome_trace"]
 
 # ----------------------------------------------------------------- taxonomy
 ARRIVAL = "arrival"
@@ -68,6 +71,12 @@ EVENT_TAXONOMY = (ARRIVAL, QUEUED, REJECTED, ADMITTED, PREFILL_CHUNK,
 # events that end a request's timeline — nothing may be stamped after one
 TERMINAL_EVENTS = frozenset({COMPLETE, DROP, REJECTED})
 
+# host phases of one engine tick (``TickRecord.spans``), each mapped to the
+# span it nests in; siblings never overlap
+TICK_PHASES = {"tick": None, "preempt": "tick", "admit": "tick",
+               "dispatch": "tick", "launch": "dispatch", "commit": "tick",
+               "commit_wait": "commit"}
+
 
 @dataclass(frozen=True)
 class SpanEvent:
@@ -89,7 +98,7 @@ class TickRecord:
     """Phase costs + batch geometry for one engine tick on one backend."""
     backend: str
     t: float                  # timeline clock at tick start (seconds)
-    kind: str                 # "fused" | "decode" | "idle"
+    kind: str                 # "fused" | "decode" | "spec" | "idle"
     preempt_ms: float = 0.0   # wall cost of the preemption phase
     admit_ms: float = 0.0     # wall cost of the admission phase
     exec_ms: float = 0.0      # wall cost of the fused-chunk / decode step
@@ -100,11 +109,6 @@ class TickRecord:
     preempted: int = 0        # requests preempted this tick
     completed: int = 0        # requests finished this tick
     pool_occupancy: float = float("nan")  # paged pool occupancy (NaN: dense)
-    # dispatch-profiler split of exec_ms (NaN unless this tick was sampled
-    # under profile_dispatch — fenced with block_until_ready)
-    dispatch_ms: float = float("nan")   # jitted call returned (async enqueue)
-    device_ms: float = float("nan")     # block_until_ready wait (device work)
-    host_sync_ms: float = float("nan")  # exec remainder: D2H copy + host loop
     # async tick loop overlap fields (NaN unless engine(async_tick=True)
     # committed a previous tick's exec on this tick): with the one-tick-lag
     # commit queue, exec_ms above is the DISPATCH phase only and the
@@ -115,6 +119,9 @@ class TickRecord:
     hidden_host_ms: float = float("nan")  # host work overlapped with t-1's
     #                                       in-flight exec (preempt + admit
     #                                       + this tick's dispatch)
+    # phase -> (start, end) on time.perf_counter, seconds (TICK_PHASES; a
+    # phase that did not run is absent)
+    spans: Dict[str, Tuple[float, float]] = field(default_factory=dict)
 
     @property
     def total_ms(self) -> float:

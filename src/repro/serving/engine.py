@@ -113,6 +113,38 @@ class _PrefillJob:
     gen: List[int] = field(default_factory=list)
 
 
+class _Span:
+    """One traced host phase of an engine tick: its start and end on
+    ``time.perf_counter`` go to ``spans[phase]`` (``TickRecord.spans``),
+    inside a ``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` that
+    puts the same phase on the profiler's clock. Built only when the
+    engine traces. The stamps sit inside the annotation: annotating a phase
+    costs its parent's time (the tick's self time, for a top-level phase),
+    never its own."""
+    __slots__ = ("spans", "phase", "ann", "t0")
+
+    def __init__(self, spans: Dict[str, Tuple[float, float]], phase: str,
+                 backend: Optional[str] = None):
+        self.spans, self.phase = spans, phase
+        name = "engine." + phase
+        self.ann = (jax.profiler.TraceAnnotation(name) if backend is None
+                    else jax.profiler.TraceAnnotation(name, backend=backend))
+
+    def __enter__(self) -> None:
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        self.spans[self.phase] = (self.t0, time.perf_counter())
+        self.ann.__exit__(*exc)
+
+
+def _span_ms(spans: Dict[str, Tuple[float, float]], phase: str) -> float:
+    """A phase's duration in ms; NaN when the phase did not run."""
+    s = spans.get(phase)
+    return (s[1] - s[0]) * 1e3 if s else float("nan")
+
+
 @dataclass
 class _PendingExec:
     """One dispatched-but-uncommitted exec phase (DESIGN.md §Async tick
@@ -171,11 +203,9 @@ class VariantBackend:
         self.metrics = self.obs.metrics
         self.tracer = self.obs.tracer
         self.windows = self.obs.windows
-        # dispatch profiler (obs.profiler): the engine arms _fence_exec on
-        # sampled ticks; _jit_exec then fences the exec-phase jit call and
-        # leaves (dispatch_ms, device_ms) on exec_split for the TickRecord
-        self._fence_exec = False
-        self.exec_split: Optional[Tuple[float, float]] = None
+        # the running tick's spans while the engine traces it (see _Span):
+        # _jit_exec and the commit's read add their own phases here
+        self._spans: Optional[Dict[str, Tuple[float, float]]] = None
         if use_pallas and not cfg.use_pallas:
             cfg = cfg.replace(use_pallas=True)
         self.cfg = cfg
@@ -237,8 +267,6 @@ class VariantBackend:
         self._pending: Optional[_PendingExec] = None
         self.slot_gen = [0] * max_batch
         self._uncommitted_done: Set[int] = set()
-        self.commit_wait_ms = float("nan")   # blocked in the commit D2H read
-        self.commit_gap_ms = float("nan")    # dispatch -> commit-read gap
         # host mirror of each bound row's device position (the paged backend
         # buckets on it; chunked fused ticks feed it as the continuation
         # offset) — maintained through admit/chunk/decode for bound rows
@@ -323,7 +351,8 @@ class VariantBackend:
         def body(carry, _):
             t, c = carry
             logits, c = step_fn(c, t)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (nxt, c), nxt
 
         if self.decode_chunk == 1:
@@ -356,8 +385,9 @@ class VariantBackend:
             jnp.where(feed_mask, cur_tok.astype(tokens.dtype), tokens[:, 0]))
         logits, cache = self._model_prefill_chunk(params, cache, tokens,
                                                   start, n_valid)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jnp.where(set_mask, tok, cur_tok), cache
+        with jax.named_scope("sample"):
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return jnp.where(set_mask, tok, cur_tok), cache
 
     @staticmethod
     def _admit_merge_fn(cache, new_cache, cur_tok, new_tok, src, mask):
@@ -768,14 +798,7 @@ class VariantBackend:
             return []
         if pending.kind == "spec":
             return self._spec_pair.commit(pending, now)
-        if self.tracer.on:
-            t0 = time.perf_counter()
-            toks = np.asarray(pending.toks)
-            t1 = time.perf_counter()
-            self.commit_wait_ms = (t1 - t0) * 1e3
-            self.commit_gap_ms = (t0 - pending.dispatched_at) * 1e3
-        else:
-            toks = np.asarray(pending.toks)
+        toks = self._read_tokens(pending.toks)
         if self.slow_factor > 1.0 and pending.kind == "decode":
             # injected straggler: effective chunk time scales by slow_factor
             time.sleep((time.perf_counter() - pending.dispatched_at)
@@ -821,22 +844,23 @@ class VariantBackend:
         return self.commit_exec(pend, now)
 
     def _jit_exec(self, call, *args):
-        """Run one exec-phase jitted call. On dispatch-sampled ticks
-        (``_fence_exec``) the call's outputs are fenced with
-        ``block_until_ready``, splitting the async-dispatch cost (the jit
-        call returning) from device compute; ``exec_split`` carries
-        (dispatch_ms, device_ms) for the tick's ``TickRecord`` — the
-        remainder of the engine-measured exec phase is the host-sync tail
-        (``np.asarray`` D2H copy + per-slot bookkeeping)."""
-        if not self._fence_exec:
+        """Run one exec-phase jitted call. In a traced tick the call is the
+        ``launch`` span: the time the runtime takes to accept the step —
+        the call returns before the device runs it, unless the launch
+        itself waits (on a donated buffer still in use, say)."""
+        if self._spans is None:
             return call(*args)
-        t0 = time.perf_counter()
-        out = call(*args)
-        t1 = time.perf_counter()
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        self.exec_split = ((t1 - t0) * 1e3, (t2 - t1) * 1e3)
-        return out
+        with _Span(self._spans, "launch", self.name):
+            return call(*args)
+
+    def _read_tokens(self, toks) -> np.ndarray:
+        """The commit's one device-to-host read, which blocks until the
+        exec that produces ``toks`` has run. In a traced tick it is the
+        ``commit_wait`` span."""
+        if self._spans is None:
+            return np.asarray(toks)
+        with _Span(self._spans, "commit_wait", self.name):
+            return np.asarray(toks)
 
     def _dispatch_chunk(self):
         """Enqueue one decode chunk; returns the UN-SYNCED device token
@@ -1477,7 +1501,7 @@ class DraftPair:
         tokens are discarded and regenerated identically on resume."""
         v, k = self.v, self.k
         m, w = self.metrics, self.windows
-        pack = np.asarray(pending.toks)
+        pack = v._read_tokens(pending.toks)
         drafts, pred = pack[:, :k], pack[:, k:]
         finished: List[Request] = []
         for slot, r, gen_id, _base_disp, rnd in pending.spec_items:
@@ -1563,7 +1587,6 @@ class InProcessServingEngine:
                  clock: Callable[[], float] = time.time,
                  trace: bool = False,
                  obs: Optional[Observability] = None,
-                 profile_dispatch: int = 0,
                  async_tick: bool = False,
                  speculative: Optional[str] = None,
                  spec_k: int = 4):
@@ -1598,11 +1621,6 @@ class InProcessServingEngine:
         self.metrics = self.obs.metrics
         self.tracer = self.obs.tracer
         self.windows = self.obs.windows
-        # dispatch profiler: every Nth tick fences its exec-phase jit call
-        # (block_until_ready) and records the dispatch/device/host-sync
-        # split on the TickRecord (0 = off; needs tracing for the records)
-        self.profile_dispatch = int(profile_dispatch)
-        self._tick_no = 0
         # async tick loop (DESIGN.md §Async tick loop): each tick dispatches
         # its exec FIRST, then commits the PREVIOUS tick's — the D2H read
         # and bookkeeping of tick t hide behind tick t+1's device compute.
@@ -1985,128 +2003,155 @@ class InProcessServingEngine:
 
     def _tick(self, now: float) -> int:
         """One scheduler-driven engine tick per backend, in four phases:
-        preempt (optional) → admit (scheduler-ordered) → prefill chunk
-        (chunked only) → decode chunk. With the default FIFO scheduler and
-        no preemption this is exactly the legacy admit+decode tick.
+        preempt (optional) → admit (scheduler-ordered) → dispatch → commit.
+        The sync default commits the exec it just dispatched; the async
+        tick commits the previous tick's. With the default FIFO scheduler
+        and no preemption this is exactly the legacy admit+decode tick.
 
-        With tracing on, each backend's tick lands one ``TickRecord``:
-        wall cost per phase (``perf_counter`` around the phase bodies),
-        batch geometry, and pool occupancy. Tracing off costs one branch
-        per phase — the bench_engine overhead gate measures this path."""
+        With tracing on, the tick and each backend's phases are ``_Span``s:
+        start and end on ``time.perf_counter`` in one ``TickRecord`` per
+        backend (``spans``, with the legacy per-phase costs derived from
+        them), and ``engine.<phase>`` profiler annotations. Tracing off
+        runs none of it: one branch per phase."""
+        if not self.tracer.on:
+            return self._tick_backends(now, None)
+        recs: List[TickRecord] = []
+        tick: Dict[str, Tuple[float, float]] = {}
+        with _Span(tick, "tick"):
+            n = self._tick_backends(now, recs)
+        for rec in recs:
+            rec.spans["tick"] = tick["tick"]
+            self.tracer.tick(rec)
+        return n
+
+    def _tick_backends(self, now: float,
+                       recs: Optional[List[TickRecord]]) -> int:
+        """Tick every backend; with ``recs`` (tracing on) time each phase
+        and append one ``TickRecord`` per backend."""
         self._rebalance_queues()
         done_before = len(self.done)
-        tron = self.tracer.on
-        self._tick_no += 1
-        # dispatch-profiler sampling: fence every Nth tick's exec call; the
-        # records only exist with tracing on, so sampling follows tron
-        fence = (tron and self.profile_dispatch > 0
-                 and self._tick_no % self.profile_dispatch == 0)
         for name, b in self.backends.items():
             q = self.queues.get(name, deque())
+            if recs is None:
+                self._preempt(name, b, q, now)
+                self._admit(b, q, now)
+                _, pend = self._dispatch(b, now)
+                self.done.extend(b.commit_exec(pend, now))
+                continue
             bdone = len(self.done)
-            n_preempted = n_admitted = 0
-            t0 = time.perf_counter() if tron else 0.0
-            if self.preemption != "none" and q:
-                # finished-but-uncommitted zombie slots are not preemptable:
-                # their request is already complete by count, only its token
-                # read-back lags (async commit lag)
-                resident = [r for s, r in enumerate(b.slot_req)
-                            if r is not None and s not in b._uncommitted_done]
-                for v in self.sched.select_victims(resident, list(q), now,
-                                                   len(b.free_slots)):
-                    n_preempted += 1
-                    if b.preempt(v, now) == "dropped":
-                        self.done.append(v)
-                        continue        # resumes later, tokens preserved
-                    tq = q
-                    if self.preemption == "migrate":
-                        # cross-variant migration: resume on a cheaper
-                        # variant via chunked prefill continuation — the
-                        # accuracy-for-latency escape hatch under deadline
-                        # pressure (stays put when nothing is cheaper)
-                        tgt = migration_target(name, self.backends,
-                                               self.queues)
-                        if tgt is not None:
-                            v.backend = tgt
-                            tq = self.queues.setdefault(tgt, deque())
-                            self.metrics.inc("requests.migrated")
-                            if self.windows.on:
-                                self.windows.inc("requests.migrated", now)
-                    tq.append(v)
-            t1 = time.perf_counter() if tron else 0.0
-            free_n = len(b.free_slots)
-            if q and free_n:
-                ordered = self.sched.order(list(q), now)
-                joiners, rest = ordered[:free_n], ordered[free_n:]
-                q.clear()
-                q.extend(rest)
-                n_admitted = len(joiners)
-                if self.sched.chunked:
-                    self.done.extend(b.admit_chunked(joiners, now))
-                elif self.async_tick and b.chunked:
-                    # async-tick headroom: monolithic admission would
-                    # prefill synchronously inside the tick; chunked
-                    # admission of the same zero-padded prompt
-                    # (right_sized stays off) defers the prefill into the
-                    # dispatch/commit pipeline with identical outputs
-                    self.done.extend(b.admit_chunked(joiners, now))
-                else:
-                    # resumed requests need prefill continuation even under
-                    # monolithic admission (preemption builds the machinery)
-                    fresh = [r for r in joiners if not r.resume_tokens]
-                    self.done.extend(b.admit(fresh, now))
-                    resumed = [r for r in joiners if r.resume_tokens]
-                    if resumed:
-                        self.done.extend(b.admit_chunked(resumed, now))
-            t2 = time.perf_counter() if tron else 0.0
-            if fence:
-                b._fence_exec, b.exec_split = True, None
+            sp = b._spans = {}
+            with _Span(sp, "preempt", name):
+                n_preempted = self._preempt(name, b, q, now)
+            with _Span(sp, "admit", name):
+                n_admitted = self._admit(b, q, now)
+            with _Span(sp, "dispatch", name):
+                kind, pend = self._dispatch(b, now)
+            if pend is not None:
+                with _Span(sp, "commit", name):
+                    self.done.extend(b.commit_exec(pend, now))
+            b._spans = None
             nan = float("nan")
             commit_ms = gap_ms = wait_ms = hidden_ms = nan
-            if self.async_tick:
-                # dispatch tick t's exec, THEN commit tick t-1's: the read
-                # + bookkeeping of t-1 hide behind t's device compute
-                pend_prev, b._pending = b._pending, None
-                kind, b._pending = b.dispatch_exec(now)
-                t3 = time.perf_counter() if tron else 0.0
-                self.done.extend(b.commit_exec(pend_prev, now))
-                if tron and pend_prev is not None:
-                    commit_ms = (time.perf_counter() - t3) * 1e3
-                    gap_ms = b.commit_gap_ms
-                    wait_ms = b.commit_wait_ms
+            if not self.async_tick:      # exec = dispatch + its own commit
+                exec_ms = (sp.get("commit", sp["dispatch"])[1]
+                           - sp["dispatch"][0]) * 1e3
+            else:
+                exec_ms = _span_ms(sp, "dispatch")
+                if pend is not None:
+                    commit_ms = _span_ms(sp, "commit")
+                    wait_ms = _span_ms(sp, "commit_wait")
+                    if "commit_wait" in sp:
+                        gap_ms = (sp["commit_wait"][0]
+                                  - pend.dispatched_at) * 1e3
                     # host work done this tick while t-1 was still in
                     # flight on the device (preempt + admit + dispatch)
-                    hidden_ms = (t3 - t0) * 1e3
-            elif b._prefilling:   # fused tick: prefill chunks + 1-tok decodes
-                kind = "fused"
-                self.done.extend(b.fused_chunk_step(now))
-                t3 = time.perf_counter() if tron else 0.0
-            else:                 # pure decode: the fast bucket-aware chunk
-                kind = "decode" if b.active_slots else "idle"
-                self.done.extend(b.decode_step_batch(now))
-                t3 = time.perf_counter() if tron else 0.0
-            if tron:
-                exec_ms = (t3 - t2) * 1e3
-                disp_ms = dev_ms = host_ms = nan
-                if fence:
-                    b._fence_exec = False
-                    if b.exec_split is not None:  # idle ticks ran no jit
-                        disp_ms, dev_ms = b.exec_split
-                        host_ms = max(exec_ms - disp_ms - dev_ms, 0.0)
-                occ = (b.kv_pool_occupancy
-                       if isinstance(b, PagedVariantBackend) else float("nan"))
-                self.tracer.tick(TickRecord(
-                    backend=name, t=now, kind=kind,
-                    preempt_ms=(t1 - t0) * 1e3, admit_ms=(t2 - t1) * 1e3,
-                    exec_ms=exec_ms, active=b.active_slots,
-                    prefilling=len(b._prefilling), queued=len(q),
-                    admitted=n_admitted, preempted=n_preempted,
-                    completed=len(self.done) - bdone, pool_occupancy=occ,
-                    dispatch_ms=disp_ms, device_ms=dev_ms,
-                    host_sync_ms=host_ms, commit_ms=commit_ms,
-                    commit_gap_ms=gap_ms, commit_wait_ms=wait_ms,
-                    hidden_host_ms=hidden_ms))
+                    hidden_ms = (sp["dispatch"][1] - sp["preempt"][0]) * 1e3
+            occ = (b.kv_pool_occupancy
+                   if isinstance(b, PagedVariantBackend) else nan)
+            recs.append(TickRecord(
+                backend=name, t=now, kind=kind,
+                preempt_ms=_span_ms(sp, "preempt"),
+                admit_ms=_span_ms(sp, "admit"), exec_ms=exec_ms,
+                active=b.active_slots,
+                prefilling=len(b._prefilling), queued=len(q),
+                admitted=n_admitted, preempted=n_preempted,
+                completed=len(self.done) - bdone, pool_occupancy=occ,
+                commit_ms=commit_ms, commit_gap_ms=gap_ms,
+                commit_wait_ms=wait_ms, hidden_host_ms=hidden_ms, spans=sp))
         return len(self.done) - done_before
+
+    def _preempt(self, name: str, b: VariantBackend, q: Deque[Request],
+                 now: float) -> int:
+        """Preemption phase: retire the scheduler's victims (requeued,
+        migrated or dropped). Returns how many were preempted."""
+        if self.preemption == "none" or not q:
+            return 0
+        # finished-but-uncommitted zombie slots are not preemptable: their
+        # request is already complete by count, only its token read-back
+        # lags (async commit lag)
+        resident = [r for s, r in enumerate(b.slot_req)
+                    if r is not None and s not in b._uncommitted_done]
+        victims = self.sched.select_victims(resident, list(q), now,
+                                            len(b.free_slots))
+        for v in victims:
+            if b.preempt(v, now) == "dropped":
+                self.done.append(v)
+                continue            # resumes later, tokens preserved
+            tq = q
+            if self.preemption == "migrate":
+                # cross-variant migration: resume on a cheaper variant via
+                # chunked prefill continuation — the accuracy-for-latency
+                # escape hatch under deadline pressure (stays put when
+                # nothing is cheaper)
+                tgt = migration_target(name, self.backends, self.queues)
+                if tgt is not None:
+                    v.backend = tgt
+                    tq = self.queues.setdefault(tgt, deque())
+                    self.metrics.inc("requests.migrated")
+                    if self.windows.on:
+                        self.windows.inc("requests.migrated", now)
+            tq.append(v)
+        return len(victims)
+
+    def _admit(self, b: VariantBackend, q: Deque[Request], now: float) -> int:
+        """Admission phase: move scheduler-ordered waiters into free slots.
+        Returns how many joined."""
+        free_n = len(b.free_slots)
+        if not (q and free_n):
+            return 0
+        ordered = self.sched.order(list(q), now)
+        joiners, rest = ordered[:free_n], ordered[free_n:]
+        q.clear()
+        q.extend(rest)
+        if self.sched.chunked:
+            self.done.extend(b.admit_chunked(joiners, now))
+        elif self.async_tick and b.chunked:
+            # async-tick headroom: monolithic admission would prefill
+            # synchronously inside the tick; chunked admission of the same
+            # zero-padded prompt (right_sized stays off) defers the prefill
+            # into the dispatch/commit pipeline with identical outputs
+            self.done.extend(b.admit_chunked(joiners, now))
+        else:
+            # resumed requests need prefill continuation even under
+            # monolithic admission (preemption builds the machinery)
+            fresh = [r for r in joiners if not r.resume_tokens]
+            self.done.extend(b.admit(fresh, now))
+            resumed = [r for r in joiners if r.resume_tokens]
+            if resumed:
+                self.done.extend(b.admit_chunked(resumed, now))
+        return len(joiners)
+
+    def _dispatch(self, b: VariantBackend, now: float
+                  ) -> Tuple[str, Optional[_PendingExec]]:
+        """Dispatch phase: enqueue this tick's exec. Returns (tick kind, the
+        exec this tick commits): the async tick parks its own exec and
+        commits the previous tick's — whose read and bookkeeping then hide
+        behind this tick's device compute — the sync tick commits its own."""
+        if not self.async_tick:
+            return b.dispatch_exec(now)
+        prev, b._pending = b._pending, None
+        kind, b._pending = b.dispatch_exec(now)
+        return kind, prev
 
     def drain(self, now: float, max_ticks: int = 10_000) -> int:
         """Tick until every queue and slot is empty."""

@@ -22,7 +22,7 @@ from conftest import MAX_NEW, PROMPT_LEN, VOCAB, tiny_engine
 from repro.obs import (Alert, BurnRateRule, CollectingSink, DecisionAudit,
                        FlightRecorder, FlightTrigger, MetricWindows,
                        NULL_WINDOWS, Observability, SLOMonitor,
-                       dispatch_floor_summary, slo_class_key)
+                       slo_class_key)
 from repro.obs.export import (assert_zero, summarize_file,
                               validate_metrics_file, validate_trace_file,
                               write_metrics_jsonl)
@@ -438,33 +438,6 @@ def test_alert_sink_flight_trigger(tmp_path):
     assert os.path.basename(fr.dumps[0]) == "FLIGHT_burn_rate_750.json"
     with open(fr.dumps[0]) as f:
         assert json.load(f)["otherData"]["burn_fast"] == 4.0
-
-
-# ------------------------------------------------------ dispatch profiler
-def test_dispatch_profiler_samples_every_nth_tick():
-    eng, _ = _run_windowed_engine(750.0, profile_dispatch=2)
-    recs = eng.tracer.ticks
-    sampled = [r for r in recs if math.isfinite(r.dispatch_ms)]
-    unsampled = [r for r in recs if not math.isfinite(r.dispatch_ms)]
-    assert sampled and unsampled            # every 2nd tick fenced
-    for r in sampled:
-        assert r.dispatch_ms >= 0 and r.device_ms >= 0
-        assert r.host_sync_ms >= 0
-        assert (r.dispatch_ms + r.device_ms + r.host_sync_ms
-                <= r.exec_ms + 1e-6)
-    summary = dispatch_floor_summary(recs)
-    assert summary
-    for d in summary.values():
-        assert d["n_sampled"] >= 1
-        assert 0.0 <= d["dispatch_frac"] <= 1.0
-        assert 0.0 <= d["host_sync_frac"] <= 1.0
-
-
-def test_dispatch_profiler_off_leaves_nan():
-    eng, _ = _run_windowed_engine(750.0)           # profile_dispatch=0
-    assert eng.tracer.ticks
-    assert all(math.isnan(r.dispatch_ms) for r in eng.tracer.ticks)
-    assert dispatch_floor_summary(eng.tracer.ticks) == {}
 
 
 # ----------------------------------------------------------- drop counters
