@@ -6,9 +6,10 @@
 For each seed, in one process: the cell is set up and served for a short
 window at its own load, exactly as a run does; the engine is freed; then the
 served tokens of the sampled requests are scored by the float32 reference
-(the program's reading) and the float8 network is put in the program's
-place at the same positions (the control's reading). One JSON line per seed
-and rung. The benchmark's own runs never run the control.
+of the configuration's architecture (the program's reading) and its float8
+network is put in the program's place at the same positions (the control's
+reading). One JSON line per seed and rung. The benchmark's own runs never
+run the control.
 """
 from __future__ import annotations
 
@@ -23,9 +24,7 @@ sys.path[:0] = [BENCH, os.path.join(BENCH, "metrics"),
                 os.path.join(os.path.dirname(BENCH), "src")]
 
 import harness  # noqa: E402
-import reference  # noqa: E402
 import traffic  # noqa: E402
-from weights import run_key  # noqa: E402
 
 
 def readings(wl, cfg, mix, seed: int, seconds: float):
@@ -39,14 +38,14 @@ def readings(wl, cfg, mix, seed: int, seconds: float):
                           fill=bool(mix.get("fill_before_window")))
     samples = cell.samples(out, cfg["check"]["sample_per_rung"], seed)
     cell.release()
+    key = cell.form.run_key(seed)
     for i, r in enumerate(cfg["rungs"]):
         smp = samples.get(r["name"], [])
         if smp:
-            yield r["name"], reference.served_gaps(
-                cell.arch[r["name"]], run_key(seed), i,
-                cfg["model"]["param_dtype"], smp, control=True,
-                max_len=cell.prompt_len + cell.max_new,
-                                  max_rows=cell.max_new)
+            yield r["name"], cell.form.served_gaps(
+                cell.arch[r["name"]], key, i, cfg["model"]["param_dtype"],
+                smp, max_len=cell.prompt_len + cell.max_new,
+                max_rows=cell.max_new, mode="fp8")
 
 
 def main() -> int:

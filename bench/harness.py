@@ -7,14 +7,17 @@ The harness's contract with the program under test (``src/repro``):
 ``slot_tokens``, ``slot_pos``, ``decode_chunk``, ``params``,
 ``device``, ``model.init`` (for the layout the weights must have) and
 ``release``; the stamps of ``Request``. Everything else (traffic, weights,
-reference, trace reduction, peaks) lives in this directory.
+reference, trace reduction, peaks) lives in this directory; what belongs to
+one architecture lives in its module (``arch_module``).
 """
 from __future__ import annotations
 
 import gc
+import importlib.util
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -31,10 +34,9 @@ ROOT = os.path.dirname(BENCH)
 import roofline  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
-from weights import Arch, make_tree, run_key  # noqa: E402
 
 WARM_RID = 1 << 40        # request ids of the warm-up, apart from the window's
-PAGED_KERNEL = "paged_flash_decode_bkhd"   # the paged decode kernel's op kind
+LLAMA = "bench/arch/llama.py"   # of a configuration that names no "arch"
 
 
 def log(msg: str) -> None:
@@ -78,13 +80,40 @@ def require_chips(n: int):
     return devs
 
 
-def arch_of(cfg: Dict, layers: int) -> Arch:
-    m = cfg["model"]
-    return Arch(d_model=m["d_model"], num_heads=m["num_heads"],
-                num_kv_heads=m["num_kv_heads"], head_dim=m["head_dim"],
-                d_ff=m["d_ff"], vocab=m["vocab_size"],
-                padded_vocab=cfg["padded_vocab"], num_layers=layers,
-                rope_theta=m["rope_theta"], norm_eps=m["norm_eps"])
+_ARCH: Dict[str, object] = {}
+
+
+def arch_module(cfg: Dict):
+    """The architecture module of a configuration: the Python file that its
+    ``"arch"`` names, relative to the repository root, else the Llama form.
+    Loaded once a path. It provides:
+
+    - ``arch_of(cfg, num_layers)``: one rung's shapes, hashable (jit takes
+      it as a static argument);
+    - ``run_key(seed)``: the key that every weight of a run comes from;
+    - ``make_tree(key, arch, rung, dtype)``: the weights in the served
+      layout, made one layer at a time under jit (arguments 1-3 static);
+    - ``served_gaps(arch, key, rung, dtype, samples, *, max_len, max_rows,
+      mode)``: the float32 reference's widest gap below its best logit over
+      the served tokens (``gap``, ``tokens``); ``mode="fp8"`` adds the
+      control's (``control_gap``);
+    - ``kv_bytes_per_token(arch)``: the cache one token holds, all layers;
+    - ``KERNELS``: the op kinds to time in a trace, each mapped to the name
+      its readings take in the trace facts (``<name>_s``, ``_calls``,
+      ``_flops``, ``_bytes``);
+    - ``step_work(arch, step)``: for one backend's ``StepRows`` in a traced
+      step, the model FLOPs of its useful tokens and, per op kind of
+      ``KERNELS``, that kernel's (FLOPs, bytes).
+    """
+    path = os.path.abspath(os.path.join(ROOT, cfg.get("arch", LLAMA)))
+    if path not in _ARCH:
+        name = "arch_" + re.sub(r"\W", "_", os.path.relpath(path, ROOT))
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod       # dataclasses look their module up
+        spec.loader.exec_module(mod)
+        _ARCH[path] = mod
+    return _ARCH[path]
 
 
 class CompileCounter:
@@ -132,6 +161,16 @@ class StepSnap:
 
 
 @dataclass
+class StepRows:
+    """One backend in one traced step, as the host saw it."""
+    kind: str                       # the tick's kind: "fused" or "decode"
+    calls: int                      # paged attention calls a layer
+    starts: List[int]               # each bound row's position before it
+    useful: List[Tuple[int, int]]   # positions [lo, hi) that serve a request
+    ticks: List                     # this backend's TickRecords of the step
+
+
+@dataclass
 class Outcome:
     stamps: Dict[int, Stamp]
     t0: float
@@ -162,6 +201,7 @@ class Cell:
         self.cfg, self.seed, self.clock = cfg, seed, clock
         self.counter = CompileCounter()
         self.split: Dict[str, float] = {}
+        self.form = arch_module(cfg)       # the architecture module
         variants, self.arch, self.rung_index = {}, {}, {}
         base = get_config(cfg["registry"])
         repl = dict(cfg["model"])
@@ -169,7 +209,7 @@ class Cell:
         for i, r in enumerate(cfg["rungs"]):
             variants[r["name"]] = (base.replace(num_layers=r["num_layers"],
                                                 **repl), r["accuracy"])
-            self.arch[r["name"]] = arch_of(cfg, r["num_layers"])
+            self.arch[r["name"]] = self.form.arch_of(cfg, r["num_layers"])
             self.rung_index[r["name"]] = i
             self.split[r["name"]] = r["share"]
         eng = cfg["engine"]
@@ -208,10 +248,10 @@ class Cell:
         the engine's first, then make each rung's on its device in one
         jitted call, in the served dtype."""
         dtype = self.cfg["model"]["param_dtype"]
-        key = run_key(self.seed)
+        key = self.form.run_key(self.seed)
         for name, b in self.engine.backends.items():
             a, idx = self.arch[name], self.rung_index[name]
-            made = jax.jit(make_tree, static_argnums=(1, 2, 3))
+            made = jax.jit(self.form.make_tree, static_argnums=(1, 2, 3))
             want = jax.eval_shape(made, key, a, idx, dtype)
             have = jax.eval_shape(b.model.init, jax.random.PRNGKey(0))
             tu = jax.tree_util
@@ -220,7 +260,7 @@ class Cell:
                 for x, y in zip(tu.tree_leaves(want), tu.tree_leaves(have)))
             if not same:
                 raise SystemExit(f"bench: the served layout of {name} is not "
-                                 f"the one weights.py makes")
+                                 f"the one {self.form.__file__} makes")
             b.params = None
             gc.collect()
             with jax.default_device(b.device):
@@ -418,7 +458,8 @@ class Cell:
         n_window = committed() - n_before
         in_use = int((jax.devices()[0].memory_stats() or {}).get(
             "bytes_in_use", 0))
-        live_kv = sum(int(b.slot_pos[s]) * self.arch[n].kv_bytes_per_token
+        live_kv = sum(int(b.slot_pos[s])
+                      * self.form.kv_bytes_per_token(self.arch[n])
                       for n, b in eng.backends.items()
                       for s, r in enumerate(b.slot_req) if r is not None)
         c1 = self.counter.snapshot()
@@ -482,28 +523,28 @@ class Cell:
 
 
 # ------------------------------------------------------------ traced window
-def work_in_trace(cell: Cell, out: Outcome) -> Dict[str, float]:
+def work_in_trace(cell: Cell, out: Outcome) -> Dict:
     """What the device was asked to do in the traced steps, from the host's
-    view of every row before and after each step: the paged decode
-    kernel's FLOPs and bytes (every call, at the lengths it is given) and
-    the model FLOPs of the tokens that serve a request.
+    view of every row before and after each step: the model FLOPs of the
+    tokens that serve a request, and each timed kernel's FLOPs and bytes,
+    as the architecture module counts them (``step_work``).
 
-    A fused tick calls the kernel once per chunk token and a decode tick
-    once per decode step, each call over every bound row, at length
-    ``start + c + 1`` for call ``c``. A row bound in this step is taken to
-    have run a whole chunk (its start is not on the host)."""
+    A fused tick calls paged attention once per chunk token and a decode
+    tick once per decode step, each call over every bound row. A row bound
+    in this step is taken to have run a whole chunk (its start is not on
+    the host)."""
     eng = cell.engine
-    kflops = kbytes = mflops = tokens = 0.0
+    mflops = tokens = 0.0
+    kernels: Dict[str, Dict[str, float]] = {}
     for prev, cur in zip(out.snaps, out.snaps[1:]):
         kinds = {t.backend: t.kind for t in cur.ticks}
         for name, rows in cur.rows.items():
             kind = kinds.get(name, "idle")
             if kind == "idle" or name not in prev.rows:
                 continue
-            b = eng.backends[name]
-            a = cell.arch[name]
-            calls = eng.prefill_chunk if kind == "fused" else b.decode_chunk
-            starts = []
+            calls = (eng.prefill_chunk if kind == "fused"
+                     else eng.backends[name].decode_chunk)
+            starts, useful = [], []
             for s, (rid, pos) in enumerate(rows):
                 if rid is None:
                     continue
@@ -513,19 +554,18 @@ def work_in_trace(cell: Cell, out: Outcome) -> Dict[str, float]:
                 st = out.stamps.get(rid)
                 if st is not None:   # useful positions: the prompt and the
                     last = st.prompt_len + st.budget - 1   # fed-back tokens
-                    for p in range(start, min(pos, last)):
-                        mflops += roofline.token_flops(
-                            a.matmul_params, a.num_layers, a.num_heads,
-                            a.head_dim, p)
-                        tokens += 1
-            for c in range(calls):
-                f, by = roofline.paged_decode_call(
-                    [st + c + 1 for st in starts], a.num_heads,
-                    a.num_kv_heads, a.head_dim)
-                kflops += f * a.num_layers
-                kbytes += by * a.num_layers
-    return {"paged_decode_flops": kflops, "paged_decode_bytes": kbytes,
-            "model_flops": mflops, "model_tokens": tokens}
+                    useful.append((start, min(pos, last)))
+                    tokens += max(0, min(pos, last) - start)
+            step = StepRows(kind, calls, starts, useful,
+                            [t for t in cur.ticks if t.backend == name])
+            f, per_kernel = cell.form.step_work(cell.arch[name], step)
+            mflops += f
+            for k, (kf, kb) in per_kernel.items():
+                acc = kernels.setdefault(k, {"flops": 0.0, "bytes": 0.0})
+                acc["flops"] += kf
+                acc["bytes"] += kb
+    return {"model_flops": mflops, "model_tokens": tokens,
+            "kernels": kernels}
 
 
 def _tick_spans(snaps: List[StepSnap]) -> List[Tuple[float, float, str]]:
@@ -567,13 +607,19 @@ def reduce_trace(cell: Cell, out: Outcome, device_kind: str) -> Dict:
     planes = sorted(tr.device_ops)
     busy = [trace_reduce.busy_ns(tr.device_ops[p], lo, hi) for p in planes]
     ops = tr.device_ops[planes[0]]
-    kern_ns, kern_n = trace_reduce.op_time_ns(
-        ops, lo, hi, lambda n: trace_reduce.op_kind(n) == PAGED_KERNEL)
     idle = trace_reduce.gaps(ops, lo, hi)
     work = work_in_trace(cell, out)
-    return dict(work, window_s=(hi - lo) * 1e-9,
-                busy_s=float(np.mean(busy)) * 1e-9,
-                paged_decode_s=kern_ns * 1e-9, paged_decode_calls=kern_n,
-                peaks=roofline.peaks(device_kind),
-                device_ops=trace_reduce.top_ops(ops, lo, hi),
-                idle_gaps=trace_reduce.longest_gaps(idle, spans))
+    red = dict(model_flops=work["model_flops"],
+               model_tokens=work["model_tokens"], kernels={},
+               window_s=(hi - lo) * 1e-9, busy_s=float(np.mean(busy)) * 1e-9,
+               peaks=roofline.peaks(device_kind),
+               device_ops=trace_reduce.top_ops(ops, lo, hi),
+               idle_gaps=trace_reduce.longest_gaps(idle, spans))
+    for kind, short in cell.form.KERNELS.items():
+        ns, n = trace_reduce.op_time_ns(
+            ops, lo, hi, lambda x, k=kind: trace_reduce.op_kind(x) == k)
+        w = work["kernels"].get(kind, {"flops": 0.0, "bytes": 0.0})
+        got = dict(s=ns * 1e-9, calls=n, **w)
+        red["kernels"][kind] = got
+        red.update({f"{short}_{x}": v for x, v in got.items()})
+    return red

@@ -8,7 +8,8 @@ The cell (a configuration under a traffic mix) is looked up by name in
 readers are found in this directory by name. Set-up builds the engine, puts
 the benchmark's weights in it and warms every program the window uses; the
 window offers the mix open loop for ``--seconds``; then the served tokens
-are checked against the float32 reference. ``--trace 1`` runs the engine
+are checked against the float32 reference of the configuration's
+architecture (``harness.arch_module``). ``--trace 1`` runs the engine
 with tracing on, records the end of the window with the JAX profiler, and
 reports the per-layer metrics instead of the end-to-end ones.
 
@@ -66,7 +67,7 @@ def facts_of(cell, out, setup_s: float) -> dict:
             "t_drained": out.t_drained, "stamps": stamps,
             "committed_in_window": out.committed_in_window,
             "ticks": out.ticks, "max_batch": cell.max_batch,
-            "kv": out.kv, "trace": None}
+            "kv": out.kv, "config": cell.cfg, "trace": None}
 
 
 def run_cell(wl, cfg, mix, args, devs, t_start: float) -> dict:
@@ -129,9 +130,9 @@ def run_cell(wl, cfg, mix, args, devs, t_start: float) -> dict:
             device.update(busy_s=red["busy_s"], window_s=red["window_s"])
             breakdown = {"device_ops": [list(x) for x in red["device_ops"]],
                          "idle_gaps": [list(x) for x in red["idle_gaps"]]}
-            log(f"trace: {red['window_s']:.3f} s, busy {red['busy_s']:.3f} s,"
-                f" paged decode {red['paged_decode_s']:.4f} s in "
-                f"{red['paged_decode_calls']} events")
+            log(f"trace: {red['window_s']:.3f} s, busy {red['busy_s']:.3f} s"
+                + "".join(f", {k} {v['s']:.4f} s in {v['calls']} events"
+                          for k, v in red["kernels"].items()))
 
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = {}
@@ -143,8 +144,7 @@ def run_cell(wl, cfg, mix, args, devs, t_start: float) -> dict:
     # ---------------------------------------------------------- the check
     samples = cell.samples(out, cfg["check"]["sample_per_rung"], args.seed)
     cell.release()
-    import reference
-    from weights import run_key
+    key = cell.form.run_key(args.seed)
     limit = cfg["check"]["max_logit_gap"]
     checks, correct = {}, True
     t = time.perf_counter()
@@ -154,7 +154,7 @@ def run_cell(wl, cfg, mix, args, devs, t_start: float) -> dict:
             checks[f"served.{r['name']}"] = {"value": 0, "limit": 1}
             correct = False
             continue
-        g = reference.served_gaps(cell.arch[r["name"]], run_key(args.seed), i,
+        g = cell.form.served_gaps(cell.arch[r["name"]], key, i,
                                   cfg["model"]["param_dtype"], smp,
                                   max_len=cell.prompt_len + cell.max_new,
                                   max_rows=cell.max_new)

@@ -1,7 +1,8 @@
-"""A rehearsal of the whole run on the CPU at toy widths (tests/tiny.json):
-engine, weights, open loop, metrics and the check, in this process. It
-proves the path and the check's power; no number here is a device number,
-and nothing is printed as a result line."""
+"""A rehearsal of the whole run on the CPU at toy widths (tests/tiny.json,
+and tests/tiny_moe.json for an architecture of its own module): engine,
+weights, open loop, metrics and the check, in this process. It proves the
+path and the check's power; no number here is a device number, and nothing
+is printed as a result line."""
 import argparse
 import os
 import shutil
@@ -29,8 +30,8 @@ LATENCY = {"end_to_end": [{"name": "ttft_p90_ms", "unit": "ms"},
                          {"name": "kv.prefix_hit_rate.ttft", "unit": "%"}]}
 
 
-def rehearse(seed, trace=0, seconds=3.0, mix="tiny_chat"):
-    cfg = harness.load_json(os.path.join(HERE, "tiny.json"))
+def rehearse(seed, trace=0, seconds=3.0, mix="tiny_chat", config="tiny"):
+    cfg = harness.load_json(os.path.join(HERE, config + ".json"))
     mix = harness.load_json(os.path.join(HERE, mix + ".json"))
     bm = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
     wl = {"name": "tiny.chat", "traffic": "tiny_chat", "chips": 1,
@@ -42,8 +43,9 @@ def rehearse(seed, trace=0, seconds=3.0, mix="tiny_chat"):
                         time.perf_counter())
 
 
-def test_rehearsal_is_correct_and_reports_every_metric(capsys):
-    res = rehearse(2**33 + 21)
+@pytest.mark.parametrize("config", ["tiny", "tiny_moe"])
+def test_rehearsal_is_correct_and_reports_every_metric(capsys, config):
+    res = rehearse(2**33 + 21, config=config)
     assert res["correct"] is True
     assert res["attempted"] > 0 and res["failed"] == 0
     assert set(res["metrics"]) == {"ttft_p90_ms", "tpot_p90_ms",
@@ -119,6 +121,21 @@ def test_check_fails_a_broken_timed_path(monkeypatch, fault):
     assert res["correct"] is False, res["checks"]
 
 
+def test_moe_check_fails_with_one_expert_permuted(monkeypatch):
+    """The MoE reference given expert 0's input weights with their rows
+    reversed: the served tokens no longer match it."""
+    form = harness.arch_module(harness.load_json(
+        os.path.join(HERE, "tiny_moe.json")))
+    right = form.layer_weights
+
+    def permuted(key, i, a, rung, dtype):
+        w = right(key, i, a, rung, dtype)
+        return dict(w, wi=w["wi"].at[0].set(w["wi"][0][::-1]))
+    monkeypatch.setattr(form, "layer_weights", permuted)
+    res = rehearse(2**33 + 21, config="tiny_moe")
+    assert res["correct"] is False, res["checks"]
+
+
 def _cli(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
     return subprocess.run(
@@ -154,3 +171,18 @@ def test_control_fails_the_limit():
     assert set(got) == {"L1", "L2"}
     for g in got.values():
         assert g["gap"] <= limit < g["control_gap"]
+
+
+def test_moe_control_fails_the_cell():
+    """The sparse-expert module's float8 control: over the limit on a rung
+    (about one token in five changes its argmax at these widths, so a rung
+    of a dozen served tokens may see none change), while the served tokens
+    keep the limit on every rung."""
+    import control
+    cfg = harness.load_json(os.path.join(HERE, "tiny_moe.json"))
+    mix = harness.load_json(os.path.join(HERE, "tiny_chat.json"))
+    got = dict(control.readings({"name": "tiny_moe.chat"}, cfg, mix, 23, 3.0))
+    limit = cfg["check"]["max_logit_gap"]
+    assert set(got) == {"L1", "L2"}
+    assert all(g["gap"] <= limit for g in got.values())
+    assert any(g["control_gap"] > limit for g in got.values())
